@@ -198,14 +198,12 @@ def main() -> None:
     args = ap.parse_args()
     n_rows = args.rows or (200_000 if args.quick else 2_000_000)
 
-    from . import vcs_tables as V
-    from repro.kernels import ops as _ops
-    # force one-time jax backend init OUTSIDE the timed cells: without
-    # JAX_PLATFORMS pinned, the first lazy jax.default_backend() pays
-    # TPU-plugin probing (hundreds of ms) inside whatever cell hits it
-    _ops.backend_uses_pallas()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.interleave:
+        # the children run the cells: this parent stays off the JAX
+        # backend, which would otherwise hold the chip they need
         if not args.hotpath_only:
             ap.error("--interleave requires --hotpath-only")
         old_rows, rows = _run_interleaved(args.interleave, n_rows,
@@ -223,6 +221,13 @@ def main() -> None:
             with open(args.json, "w") as f:
                 json.dump(payload, f, indent=1)
         return
+
+    from . import vcs_tables as V
+    from repro.kernels import ops as _ops
+    # force one-time jax backend init OUTSIDE the timed cells: without
+    # JAX_PLATFORMS pinned, the first lazy jax.default_backend() pays
+    # TPU-plugin probing (hundreds of ms) inside whatever cell hits it
+    _ops.backend_uses_pallas()
 
     if args.hotpath_only:
         run_once = lambda: (V.diff_merge_hotpath(n_rows)

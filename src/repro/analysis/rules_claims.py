@@ -37,7 +37,7 @@ PRODUCER_MODULES = frozenset({
 #: hot-path modules where a hidden sort undoes the zero-rehash wins
 HOT_MODULES = frozenset({
     "repro.core.delta", "repro.core.merge", "repro.core.engine",
-    "repro.kernels.ops", "repro.kernels.probe",
+    "repro.kernels.ops", "repro.kernels.search",
     "repro.distributed.sharding",
 })
 

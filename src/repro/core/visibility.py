@@ -7,7 +7,7 @@ A row r of data object o is visible in directory d iff
 
 Tombstone membership tests are range queries on the per-directory sorted
 target array (objects own contiguous rowid ranges), served by the
-``searchsorted`` kernel via ``ops.lower_bound``.
+device lower bound (``kernels/search.py``) via ``ops.lower_bound``.
 
 Hot-path design (ISSUE 1): the sorted target array depends only on
 ``(d.tomb_oids, d.ts)`` and the immutable tombstone objects, so it is built

@@ -35,6 +35,7 @@ import contextlib
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..kernels import ops
@@ -364,6 +365,8 @@ class PullRequest:
                     ok = True if ok is None else bool(ok)
                     results.append(CheckResult(
                         name, ok, None if ok else "check returned falsy"))
+                except jax.errors.JaxRuntimeError:
+                    raise                      # the device failed, not the check
                 except Exception as exc:       # a failing check, not a bug
                     results.append(CheckResult(
                         name, False, f"{type(exc).__name__}: {exc}"))

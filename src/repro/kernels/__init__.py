@@ -1,11 +1,14 @@
-"""Pallas TPU kernels for the version-control hot paths + jit'd wrappers.
+"""Device kernels for the version-control hot paths + jit'd wrappers.
 
-Kernels (each <name>.py has the pl.pallas_call + BlockSpec tiling, ref.py has
-the pure-jnp oracle, ops.py the dispatching wrappers):
+Each <name>.py holds one device program, ref.py the pure-jnp oracle, and
+ops.py the dispatching wrappers (numpy on CPU, the device on TPU):
 
-  * rowhash         — 128-bit row/key signatures from uint32 column lanes.
-  * searchsorted    — branchless vectorized lower-bound probes.
-  * segsum_diff     — the diff-aggregation operator (boundary + signed scan).
+  * rowhash         — 128-bit row/key signatures from uint32 column lanes
+                      (Pallas).
+  * search          — lower bound and fused (start, count) probe over
+                      sorted multi-lane keys (XLA gathers; any table size).
+  * segsum_diff     — run boundaries of the diff-aggregation operator
+                      (Pallas).
   * flash_attention — online-softmax attention with VMEM-resident tiles
                       (the model-side hot spot; ops.attention dispatches).
 """

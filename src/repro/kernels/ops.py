@@ -1,13 +1,14 @@
 """Jit'd wrappers + backend dispatch for the VCS kernels.
 
-The version-control engine (``repro.core``) calls these three ops on its hot
-paths. On a TPU backend they run the Pallas kernels (``rowhash.py``,
-``searchsorted.py``, ``segsum_diff.py``); on CPU they run semantically
-identical vectorized fast paths (numpy / the pure-jnp oracle in ``ref.py``)
-so that benchmarks on this container measure algorithmic behaviour, not
-Pallas interpret-mode overhead. Setting ``FORCE_PALLAS_INTERPRET = True``
-routes everything through the Pallas kernels in interpret mode (used by
-tests to exercise the real kernels end-to-end).
+The version-control engine (``repro.core``) calls these ops on its hot
+paths. On a TPU backend they run on the device: the Pallas kernels
+``rowhash.py`` and ``segsum_diff.py``, and the XLA search programs in
+``search.py``. On CPU they run semantically identical vectorized numpy
+paths, so that tests on the CPU measure algorithmic behaviour, not Pallas
+interpret-mode overhead. Setting ``FORCE_PALLAS_INTERPRET = True`` (tests
+only) routes everything through the device path on the CPU, with the
+Pallas kernels in interpret mode. There is no fallback: on a TPU the
+device path is the only path, and its errors propagate.
 
 Signature convention: a 64-bit word is carried host-side as numpy uint64;
 kernels see it as (hi32, lo32) uint32 lanes. A row signature is 128 bits =
@@ -22,13 +23,20 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .probe import probe_pallas, DEFAULT_BLOCK_Q as PROBE_BLOCK_Q
 from .rowhash import rowhash_pallas, DEFAULT_BLOCK_ROWS
-from .searchsorted import searchsorted_pallas, DEFAULT_BLOCK_Q
-from .segsum_diff import segsum_pallas, DEFAULT_BLOCK
+from .search import lower_bound_lanes, probe_lanes
+from .segsum_diff import (boundary_pallas, LANES,
+                          DEFAULT_BLOCK_ROWS as SEG_BLOCK_ROWS)
 
-# Toggled by tests; on a real TPU backend the pallas path is the default.
+# Toggled by tests; on a real TPU backend the device path is the default.
 FORCE_PALLAS_INTERPRET = False
+
+#: smallest padded query batch of the device search
+QUERY_BLOCK = 1024
+_ALL_ONES = np.uint64(0xFFFFFFFFFFFFFFFF)
+#: rows hashed per device call, so device memory stays bounded however
+#: large the batch (a whole table load hashes in one ``rowhash`` call)
+ROWHASH_CHUNK = 1 << 18
 
 
 def backend_uses_pallas() -> bool:
@@ -37,6 +45,36 @@ def backend_uses_pallas() -> bool:
 
 def _interp() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _device_lanes(*words: np.ndarray):
+    """uint64 words -> one tuple of device uint32 lanes, each word as
+    (hi32, lo32), most significant lane first."""
+    return tuple(jnp.asarray(lane) for w in words for lane in unpack64(w))
+
+
+def _pow2(n: int, least: int = 1) -> int:
+    return max(least, 1 << (int(n) - 1).bit_length())
+
+
+def _search_table(*words: np.ndarray):
+    """Device lanes of a sorted table padded to a power of two with
+    all-ones sentinel keys, so that each search program compiles once per
+    size class. No sentinel sorts below a real key, so lower bounds are
+    exact; bounds that count rows <= the all-ones key clamp to the real
+    length on the host."""
+    n = words[0].shape[0]
+    size = _pow2(n)
+    return _device_lanes(*(_pad_rows(np.asarray(w, np.uint64), size,
+                                     fill=_ALL_ONES) for w in words))
+
+
+def _search_queries(*words: np.ndarray):
+    """Device lanes of a query batch padded to a power of two rows (at
+    least ``QUERY_BLOCK``); the padded answers are sliced off."""
+    size = _pow2(words[0].shape[0], QUERY_BLOCK)
+    return _device_lanes(*(_pad_rows(np.asarray(w, np.uint64), size)
+                           for w in words))
 
 
 # ---------------------------------------------------------------- packing
@@ -67,9 +105,17 @@ def rowhash(lanes_u32: np.ndarray) -> np.ndarray:
     if r == 0:
         return np.zeros((0, 4), np.uint32)
     if backend_uses_pallas():
-        padded = _pad_rows(np.asarray(lanes_u32, np.uint32), DEFAULT_BLOCK_ROWS)
-        out = rowhash_pallas(jnp.asarray(padded), interpret=_interp())
-        return np.asarray(out)[:r]
+        lanes = np.asarray(lanes_u32, np.uint32)
+        c = lanes.shape[1]
+        out = np.empty((r, 4), np.uint32)
+        for s in range(0, r, ROWHASH_CHUNK):
+            part = lanes[s:s + ROWHASH_CHUNK]
+            k = part.shape[0]
+            cols = np.ascontiguousarray(
+                _pad_rows(part, DEFAULT_BLOCK_ROWS).T).reshape(c, -1, LANES)
+            res = rowhash_pallas(jnp.asarray(cols), interpret=_interp())
+            out[s:s + k] = np.asarray(res).reshape(4, -1)[:, :k].T
+        return out
     # CPU fast path: identical math in numpy (wrapping uint32).
     return _rowhash_np(np.asarray(lanes_u32, np.uint32))
 
@@ -116,20 +162,17 @@ def lower_bound(sorted_u64: np.ndarray, queries_u64: np.ndarray) -> np.ndarray:
     if queries_u64.shape[0] == 0 or sorted_u64.shape[0] == 0:
         return np.zeros(queries_u64.shape, np.int64)
     if backend_uses_pallas():
-        t_hi, t_lo = unpack64(np.asarray(sorted_u64))
-        q_hi, q_lo = unpack64(_pad_rows(np.asarray(queries_u64), DEFAULT_BLOCK_Q,
-                                        fill=np.uint64(0)))
-        idx = searchsorted_pallas(jnp.asarray(t_hi), jnp.asarray(t_lo),
-                                  jnp.asarray(q_hi), jnp.asarray(q_lo),
-                                  interpret=_interp())
-        return np.asarray(idx[: queries_u64.shape[0]], np.int64)
+        nq = queries_u64.shape[0]
+        idx = lower_bound_lanes(_search_table(sorted_u64),
+                                _search_queries(queries_u64))
+        return np.asarray(idx)[:nq].astype(np.int64)
     return np.searchsorted(sorted_u64, queries_u64, side="left").astype(np.int64)
 
 
 def upper_bound(sorted_u64: np.ndarray, queries_u64: np.ndarray) -> np.ndarray:
     """First index i with sorted[i] > q, per query. Returns int64 indices.
 
-    Served by the same searchsorted kernel: ub(q) == lb(q + 1) for any q
+    Served by the same device lower bound: ub(q) == lb(q + 1) for any q
     below the uint64 maximum (equal-key runs are what the probe paths
     resolve vectorized with lb/ub pairs)."""
     if queries_u64.shape[0] == 0 or sorted_u64.shape[0] == 0:
@@ -148,7 +191,7 @@ def searchsorted128(t_lo: np.ndarray, t_hi: np.ndarray,
                     side: str = "left") -> np.ndarray:
     """Exact 128-bit searchsorted against a stream sorted by (lo, hi).
 
-    Primary ranks come from the 64-bit searchsorted kernel on the lo word.
+    Primary ranks come from the 64-bit lower bound on the lo word.
     Queries whose lo word exists in the table — the COMMON case for the
     merge-join and rank-sum callers, where most queries are exact key
     matches — refine against the hi word in one vectorized gather+compare
@@ -188,12 +231,12 @@ def probe128(t_lo: np.ndarray, t_hi: np.ndarray,
     upper_bound → segment_expand → reduceat chain: the run of rows exactly
     equal to the query is ``[start, start + cnt)``, contiguous because
     sealed objects sort by (lo, hi). Queries SHOULD arrive sorted by
-    (lo, hi) — correctness never depends on it, but the kernel's per-block
-    descents and the CPU searchsorted both degrade on shuffled batches
-    (documented probe contract, ROADMAP §Performance).
+    (lo, hi) — correctness never depends on it, but the CPU searchsorted
+    degrades on shuffled batches (documented probe contract, ROADMAP
+    §Performance).
 
-    Backend dispatch: on Pallas both bounds come out of one fused
-    fixed-depth kernel descent over the four uint32 lanes; on CPU one lo64
+    Backend dispatch: on the device both bounds come out of one fused
+    fixed-depth descent over the four uint32 lanes; on CPU one lo64
     searchsorted resolves every query whose lo64 run has length 1 (the
     common case for hashed keys) and only genuine lo64 collisions pay the
     vectorized hi-word refinement."""
@@ -202,19 +245,11 @@ def probe128(t_lo: np.ndarray, t_hi: np.ndarray,
     if nq == 0 or n == 0:
         return np.zeros((nq,), np.int64), np.zeros((nq,), np.int64)
     if backend_uses_pallas():
-        t_lh, t_ll = unpack64(np.asarray(t_lo))
-        t_hh, t_hl = unpack64(np.asarray(t_hi))
-        q_lh, q_ll = unpack64(_pad_rows(np.asarray(q_lo), PROBE_BLOCK_Q,
-                                        fill=np.uint64(0)))
-        q_hh, q_hl = unpack64(_pad_rows(np.asarray(q_hi), PROBE_BLOCK_Q,
-                                        fill=np.uint64(0)))
-        start, cnt = probe_pallas(
-            jnp.asarray(t_lh), jnp.asarray(t_ll),
-            jnp.asarray(t_hh), jnp.asarray(t_hl),
-            jnp.asarray(q_lh), jnp.asarray(q_ll),
-            jnp.asarray(q_hh), jnp.asarray(q_hl), interpret=_interp())
-        return (np.asarray(start[:nq], np.int64),
-                np.asarray(cnt[:nq], np.int64))
+        start, ub = probe_lanes(_search_table(t_lo, t_hi),
+                                _search_queries(q_lo, q_hi))
+        start = np.asarray(start)[:nq].astype(np.int64)
+        ub = np.minimum(np.asarray(ub)[:nq], n).astype(np.int64)
+        return start, ub - start
     # CPU fused fast path: one primary-word searchsorted for everything
     lb = np.searchsorted(t_lo, q_lo, side="left").astype(np.int64)
     start = lb.copy()
@@ -393,8 +428,8 @@ def merge128_runs(lo: np.ndarray, hi: np.ndarray,
     merge (see ``_merge128_sharded``), so multi-device backends can split
     by key range and CPU gets cache-sized partitions for free.
 
-    Backend dispatch: on the Pallas backend the runs are merged by
-    searchsorted rank-sums (k passes of the searchsorted kernel, no sort at
+    Backend dispatch: on the device backend the runs are merged by
+    searchsorted rank-sums (k passes of the device lower bound, no sort at
     all); on CPU the run-aware stable argsort is measurably faster (timsort's
     galloping merge on run-structured input: ~4ms vs ~40ms per 200k rows x 9
     runs), so the rank-sum path is reserved for the kernel backend."""
@@ -501,11 +536,10 @@ def _shard_slices(s_lo: np.ndarray, s_hi: np.ndarray,
     return aligned
 
 
-def _boundary_flags(s_lo: np.ndarray, s_hi: np.ndarray,
-                    s_sg: np.ndarray) -> np.ndarray:
+def _boundary_flags(s_lo: np.ndarray, s_hi: np.ndarray) -> np.ndarray:
     """New-run boundary flags of one sorted slice (backend dispatch)."""
     if backend_uses_pallas():
-        return _segsum_boundary(s_lo, s_hi, s_sg)
+        return _segsum_boundary(s_lo, s_hi)
     n = s_lo.shape[0]
     neq = np.empty((n,), bool)
     neq[0] = True
@@ -543,42 +577,37 @@ def diff_aggregate(sig_lo: np.ndarray, sig_hi: np.ndarray,
             bnd = np.empty((n,), bool)
             edges = np.concatenate([[0], starts, [n]])
             for a, b in zip(edges[:-1], edges[1:]):
-                bnd[a:b] = _boundary_flags(s_lo[a:b], s_hi[a:b], s_sg[a:b])
+                bnd[a:b] = _boundary_flags(s_lo[a:b], s_hi[a:b])
             return order, DiffAgg(bnd, s_sg)
 
-    return order, DiffAgg(_boundary_flags(s_lo, s_hi, s_sg), s_sg)
+    return order, DiffAgg(_boundary_flags(s_lo, s_hi), s_sg)
 
 
-def _segsum_boundary(s_lo: np.ndarray, s_hi: np.ndarray,
-                     s_sg: np.ndarray) -> np.ndarray:
+def _segsum_boundary(s_lo: np.ndarray, s_hi: np.ndarray) -> np.ndarray:
     """New-run boundary flags of a sorted stream via the segsum kernel."""
     n = s_lo.shape[0]
-    lo_hi32, lo_lo32 = unpack64(s_lo)
-    hi_hi32, hi_lo32 = unpack64(s_hi)
-    keys = np.stack([lo_lo32, lo_hi32, hi_lo32, hi_hi32], axis=1)
-    keys_p = _pad_rows(keys, DEFAULT_BLOCK, fill=np.uint32(0xFFFFFFFF))
-    sg_p = _pad_rows(s_sg, DEFAULT_BLOCK)
-    nblocks = keys_p.shape[0] // DEFAULT_BLOCK
-    prev_last = np.empty((nblocks, 4), np.uint32)
-    prev_last[0] = np.uint32(0xFFFFFFFF)  # forces boundary at row 0 unless
-    # keys[0] == all-ones sentinel; patched below.
-    if nblocks > 1:
-        prev_last[1:] = keys_p[np.arange(1, nblocks) * DEFAULT_BLOCK - 1]
-    bnd, _csum, _tot = segsum_pallas(jnp.asarray(keys_p),
-                                     jnp.asarray(prev_last),
-                                     jnp.asarray(sg_p), interpret=_interp())
-    bnd = np.array(bnd[:n])  # copy: jax buffers are read-only
+    rows = -(-n // LANES)
+    block_rows = min(SEG_BLOCK_ROWS, -(-rows // 8) * 8)
+    block = block_rows * LANES
+    lanes = [_pad_rows(x, block).reshape(-1, LANES)
+             for w in (s_lo, s_hi) for x in unpack64(w)]
+    flags = boundary_pallas(*(jnp.asarray(x) for x in lanes),
+                            block_rows=block_rows, interpret=_interp())
+    bnd = np.asarray(flags).reshape(-1)[:n] != 0
+    # a block's first element has its predecessor in the previous block
+    first = np.arange(block, n, block)
+    bnd[first] = ((s_lo[first] != s_lo[first - 1])
+                  | (s_hi[first] != s_hi[first - 1]))
     bnd[0] = True
     return bnd
 
 
-def _boundary_flags_rows(k_lo, k_hi, r_lo, r_hi, s_sg,
-                         same: bool) -> np.ndarray:
+def _boundary_flags_rows(k_lo, k_hi, r_lo, r_hi, same: bool) -> np.ndarray:
     """(key OR row)-change boundary flags of one key-sorted slice."""
     if backend_uses_pallas():
-        bnd = _segsum_boundary(k_lo, k_hi, s_sg)
+        bnd = _segsum_boundary(k_lo, k_hi)
         if not same:
-            bnd |= _segsum_boundary(r_lo, r_hi, s_sg)
+            bnd |= _segsum_boundary(r_lo, r_hi)
         return bnd
     n = k_lo.shape[0]
     neq = np.empty((n,), bool)
@@ -632,12 +661,11 @@ def diff_aggregate_rows(key_lo: np.ndarray, key_hi: np.ndarray,
             edges = np.concatenate([[0], starts, [n]])
             for a, b in zip(edges[:-1], edges[1:]):
                 bnd[a:b] = _boundary_flags_rows(
-                    k_lo[a:b], k_hi[a:b], r_lo[a:b], r_hi[a:b],
-                    s_sg[a:b], same)
+                    k_lo[a:b], k_hi[a:b], r_lo[a:b], r_hi[a:b], same)
             return order, DiffAgg(bnd, s_sg)
 
     return order, DiffAgg(
-        _boundary_flags_rows(k_lo, k_hi, r_lo, r_hi, s_sg, same), s_sg)
+        _boundary_flags_rows(k_lo, k_hi, r_lo, r_hi, same), s_sg)
 
 
 # --------------------------------------------------------- attention entry

@@ -1,20 +1,16 @@
-"""Pallas TPU kernel: the diff-aggregation operator (paper §5.1).
+"""Pallas TPU kernel: run boundaries of the diff-aggregation operator (§5.1).
 
-Input is a signed stream sorted by 128-bit key: rows from the right snapshot
-carry sign +1, rows from the left snapshot carry -1. Identical changes on the
-two sides must cancel. The kernel computes, per element:
+Input is a signed stream sorted by 128-bit key. Equal keys form one run, and
+``ops.DiffAgg`` nets each run's signs on the host from the boundary flags
+this kernel produces: 1 where an element's key differs from its
+predecessor's.
 
-  * ``boundary`` — True where a new key-run starts, and
-  * ``csum``     — block-local inclusive cumulative sum of signs.
-
-``ops.diff_aggregate`` composes blocks with a two-phase scan: the kernel
-emits per-block partial sums, the (tiny) block-offset scan happens in jnp,
-so the kernel stays embarrassingly parallel over the grid — this mirrors the
-classic TPU segmented-scan decomposition rather than a sequential carry.
-
-Boundary detection across block edges uses an explicitly passed
-``prev_last`` row (the key preceding the block), avoiding overlapping
-BlockSpecs.
+Layout: each of the four uint32 key lanes arrives as an (R, 128) array in
+row-major element order, tiled over the grid in (block_rows, 128) VMEM
+blocks. The predecessor of element (r, c) is (r, c - 1), or (r - 1, 127)
+at c == 0; two rotations (lanes, then sublanes) line it up. The first
+element of each block has its predecessor in the previous block, so its
+flag here is meaningless and the caller sets it.
 """
 from __future__ import annotations
 
@@ -23,52 +19,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 2048
-
-
-def _segsum_kernel(keys_ref, prev_ref, signs_ref, bnd_ref, csum_ref, tot_ref):
-    keys = keys_ref[...]          # (B, 4) uint32
-    prev_last = prev_ref[...]     # (1, 4) uint32 — key before this block
-    signs = signs_ref[...]        # (B,) int32
-    prev = jnp.concatenate([prev_last, keys[:-1]], axis=0)
-    bnd_ref[...] = jnp.any(keys != prev, axis=1)
-    cs = jnp.cumsum(signs, axis=0, dtype=jnp.int32)
-    csum_ref[...] = cs
-    tot_ref[...] = cs[-1:]        # (1,) block total for the phase-2 scan
+LANES = 128
+DEFAULT_BLOCK_ROWS = 256  # 32Ki keys per grid step
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def segsum_pallas(keys: jnp.ndarray, prev_last: jnp.ndarray,
-                  signs: jnp.ndarray, *, block: int = DEFAULT_BLOCK,
-                  interpret: bool = False):
-    """keys: (N, 4) uint32 sorted; prev_last: (nblocks, 4) uint32 with the key
-    preceding each block (block 0 row = anything unequal to keys[0] or the
-    caller marks boundary explicitly); signs: (N,) int32.
+def _boundary_kernel(k0_ref, k1_ref, k2_ref, k3_ref, out_ref):
+    shape = out_ref.shape
+    col0 = jax.lax.broadcasted_iota(jnp.int32, shape, 1) == 0
+    ne = None
+    for ref in (k0_ref, k1_ref, k2_ref, k3_ref):
+        x = ref[...]
+        left = pltpu.roll(x, 1, 1)     # x[r, c - 1]  (c == 0: x[r, 127])
+        up = pltpu.roll(left, 1, 0)    # x[r - 1, 127] at c == 0
+        d = x != jnp.where(col0, up, left)
+        ne = d if ne is None else ne | d
+    out_ref[...] = ne.astype(jnp.int32)
 
-    Returns (boundary (N,) bool, csum_local (N,) int32, block_tot (nblocks,)
-    int32)."""
-    n = keys.shape[0]
-    assert n % block == 0, (n, block)
-    nblocks = n // block
-    grid = (nblocks,)
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def boundary_pallas(k0: jnp.ndarray, k1: jnp.ndarray, k2: jnp.ndarray,
+                    k3: jnp.ndarray, *, block_rows: int = DEFAULT_BLOCK_ROWS,
+                    interpret: bool = False) -> jnp.ndarray:
+    """k0..k3: (R, 128) uint32 key lanes of a sorted stream, R a multiple
+    of ``block_rows`` (itself a multiple of 8). Returns (R, 128) int32:
+    1 where the key differs from its predecessor's, except at each block's
+    first element (see the module docstring)."""
+    r = k0.shape[0]
+    assert k0.shape[1] == LANES and r % block_rows == 0, (k0.shape,
+                                                          block_rows)
+    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
-        _segsum_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, 4), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (i, 0)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.bool_),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks,), jnp.int32),
-        ],
+        _boundary_kernel,
+        grid=(r // block_rows,),
+        in_specs=[spec] * 4,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.int32),
         interpret=interpret,
-    )(keys, prev_last, signs)
+    )(k0, k1, k2, k3)

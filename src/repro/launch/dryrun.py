@@ -29,13 +29,6 @@ from . import steps as S
 from .hlo_analysis import analyze_hlo
 from .mesh import HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16, make_production_mesh
 
-def _mesh_context(mesh):
-    """``jax.set_mesh`` context on new jax; the Mesh itself (a context
-    manager with the same lowering effect) on jax<=0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 COLLECTIVE_RE = re.compile(
     r"\b(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start)?\b")
@@ -80,7 +73,7 @@ def analyze_cell(arch: str, shape_name: str, *, multi_pod: bool,
     mesh = mesh or make_production_mesh(multi_pod=multi_pod)
     n_chips = int(np.prod(list(mesh.shape.values())))
     t0 = time.perf_counter()  # monotonic: a wall-clock step breaks timings
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step, st_specs, in_sh = S.make_train_step(
                 cfg, shape, mesh, sc=sc, n_micro=n_micro,

@@ -24,11 +24,8 @@ BF16 = jnp.bfloat16
 
 
 def as_shardings(tree, mesh: Mesh):
-    """jax<=0.4 requires concrete ``Sharding``s in ``jax.jit``'s
-    in/out_shardings; newer jax accepts bare PartitionSpecs (under
-    ``jax.set_mesh``). Convert specs on old jax, pass through on new."""
-    if hasattr(jax, "set_mesh"):
-        return tree
+    """PartitionSpecs in ``tree`` -> ``NamedSharding``s on ``mesh``, so
+    ``jax.jit``'s in/out_shardings need no ambient mesh."""
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s) if isinstance(s, P) else s,
         tree, is_leaf=lambda s: isinstance(s, P))

@@ -1,5 +1,6 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp oracle vs the
-numpy fast path, swept over shapes/dtypes."""
+"""Per-kernel validation: device kernels (Pallas in interpret=True mode, the
+XLA search on the CPU backend) vs pure-jnp oracle vs the numpy fast path,
+swept over shapes/dtypes."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -7,8 +8,7 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.rowhash import rowhash_pallas
-from repro.kernels.searchsorted import searchsorted_pallas
-from repro.kernels.segsum_diff import segsum_pallas
+from repro.kernels.search import lower_bound_lanes
 
 
 @pytest.mark.parametrize("rows,lanes", [(1024, 2), (2048, 6), (3072, 24),
@@ -16,7 +16,9 @@ from repro.kernels.segsum_diff import segsum_pallas
 def test_rowhash_pallas_vs_ref(rows, lanes):
     rng = np.random.default_rng(rows + lanes)
     x = rng.integers(0, 2**32, size=(rows, lanes), dtype=np.uint32)
-    out_k = np.asarray(rowhash_pallas(jnp.asarray(x), interpret=True))
+    cols = jnp.asarray(np.ascontiguousarray(x.T).reshape(lanes, -1, 128))
+    out_k = np.asarray(rowhash_pallas(cols, block_rows=1024, interpret=True)
+                       ).reshape(4, rows).T
     out_r = np.asarray(ref.rowhash(jnp.asarray(x)))
     out_n = ops._rowhash_np(x)
     assert np.array_equal(out_k, out_r)
@@ -48,16 +50,14 @@ def test_searchsorted_pallas_vs_numpy(n, q):
         rng.choice(tab, size=q // 2),
         rng.integers(0, 2**63, size=q // 2 - 2).astype(np.uint64),
         np.asarray([0, 2**63 - 1], np.uint64)])
-    t_hi, t_lo = ops.unpack64(tab)
-    q_hi, q_lo = ops.unpack64(queries)
-    got = np.asarray(searchsorted_pallas(
-        jnp.asarray(t_hi), jnp.asarray(t_lo), jnp.asarray(q_hi),
-        jnp.asarray(q_lo), interpret=True))
+    got = np.asarray(lower_bound_lanes(ops._device_lanes(tab),
+                                       ops._device_lanes(queries)))
     want = np.searchsorted(tab, queries, side="left")
     assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("n,card", [(2048, 3), (4096, 100), (2048, 2048)])
+@pytest.mark.parametrize("n,card", [(2048, 3), (4096, 100), (2048, 2048),
+                                    (70_000, 9_000)])
 def test_segsum_pallas_vs_oracle(n, card):
     rng = np.random.default_rng(n + card)
     keys64 = np.sort(rng.integers(0, card, size=n).astype(np.uint64))

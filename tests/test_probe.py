@@ -114,8 +114,9 @@ def test_probe128_property(tbl, qry):
 
 
 def test_probe128_interpret_matches_cpu():
-    """The Pallas kernel (interpret mode) agrees with the numpy fallback,
-    including on duplicate runs that straddle the query padding block."""
+    """The device search (run here on the CPU backend) agrees with the
+    numpy fallback, including on duplicate runs that straddle the query
+    padding block."""
     rng = np.random.default_rng(list(b"PROBEK"))
     t_lo, t_hi = _sorted_table(rng, 700, 23, 5)
     q_lo = rng.integers(0, 25, 333).astype(np.uint64)
@@ -129,6 +130,31 @@ def test_probe128_interpret_matches_cpu():
         ops.FORCE_PALLAS_INTERPRET = prev
     np.testing.assert_array_equal(got[0], want[0])
     np.testing.assert_array_equal(got[1], want[1])
+
+
+def test_device_search_all_ones_keys_beside_sentinel_padding():
+    """The device search pads tables to a power of two with all-ones
+    sentinel keys: real all-ones keys (a run at the table's end) and
+    all-ones queries must still get exact bounds and run lengths."""
+    top = np.uint64(0xFFFFFFFFFFFFFFFF)
+    t_lo = np.array([1, 5, 5, top, top, top], np.uint64)
+    t_hi = np.array([0, 2, 3, 7, top, top], np.uint64)
+    q_lo = np.array([top, top, top, 5, 0, 6], np.uint64)
+    q_hi = np.array([top, 7, 8, 3, 0, 0], np.uint64)
+    prev = ops.FORCE_PALLAS_INTERPRET
+    ops.FORCE_PALLAS_INTERPRET = True
+    try:
+        got = ops.probe128(t_lo, t_hi, q_lo, q_hi)
+        lb = ops.lower_bound(t_lo, q_lo)
+        ub = ops.upper_bound(t_lo, q_lo)
+    finally:
+        ops.FORCE_PALLAS_INTERPRET = prev
+    want = _chained_probe(t_lo, t_hi, q_lo, q_hi)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], [2, 1, 0, 1, 0, 0])
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(lb, np.searchsorted(t_lo, q_lo, "left"))
+    np.testing.assert_array_equal(ub, np.searchsorted(t_lo, q_lo, "right"))
 
 
 # ============================================== probe edge cases (visibility)
@@ -348,7 +374,7 @@ def test_end_to_end_sharded_workload_identical(pk):
     """The full engine under a forced 4-way shard plan produces the same
     diff/merge/scan digests as the unsharded run — sharding is a plan,
     never a semantic."""
-    from test_diff_digest import run_workload
+    from benchmarks.digests import run_workload
     want = run_workload(pk, n_rows=20_000, csize=1_500)
     prev = sharding.set_key_shards(4)
     try:
@@ -362,7 +388,7 @@ def test_forced_shards_delta_digest_and_counter():
     """A forced shard plan partitions the Δ merge (multi-object signed
     stream) without changing the diff, and the shard_parts counter moves."""
     from repro.core import snapshot_diff
-    from test_diff_digest import diff_digest
+    from benchmarks.digests import diff_digest
 
     def build():
         e = Engine()

@@ -159,6 +159,28 @@ def test_check_exception_is_a_failure_and_preview_is_ephemeral():
         WAL.deserialize(e.wal.serialize())).ts
 
 
+def test_device_error_in_check_propagates():
+    """A device failure inside a check is an error, not a failed check:
+    it reaches the caller, and the preview is still cleaned up."""
+    import jax
+    e = mk_engine()
+    e.create_branch("dev", ["t"])
+    e.insert("dev/t", _batch([4]))
+    pr = e.open_pr("main", "dev")
+    oids = set(e.store.oids())
+
+    def device_fault(ctx):
+        raise jax.errors.JaxRuntimeError("INTERNAL: device halted")
+
+    pr.add_check(device_fault, "device")
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        pr.run_checks()
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        pr.publish()
+    assert set(e.store.oids()) == oids and pr.status == "open"
+    assert e.table("t").count() == 3
+
+
 # -------------------------------------------------- publish atomicity
 
 def test_conflict_in_second_table_unwinds_whole_publish():
