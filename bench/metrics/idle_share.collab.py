@@ -1,0 +1,3 @@
+"""Percent of the traced window in which no operation ran on the device,
+in the collaborative cells."""
+from bench.readers import idle_share as read  # noqa: F401

@@ -1,0 +1,3 @@
+"""Plain references of the benchmark's configurations, one module each,
+named by the ``reference`` key of a configuration file. A reference imports
+nothing of the program under test."""
