@@ -1,0 +1,7 @@
+"""MiB the dispatch layer copied to the device per point read, padding
+included (``xfer.h2d_bytes``)."""
+from bench.program_readers import mib_per
+
+
+def read(ctx):
+    return mib_per(ctx, "xfer.h2d_bytes", "reads")

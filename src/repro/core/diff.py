@@ -27,6 +27,8 @@ from .delta import DeltaStats, SignedStream, full_scan_stream, signed_delta
 
 SP_DIFF = telemetry.register_span(
     "diff", "SNAPSHOT DIFF: Δ-scan + diff aggregation")
+SP_GATHER = telemetry.register_span(
+    "gather_payload", "materialize full rows by physical rowid")
 from .directory import Snapshot
 from .objects import ObjectStore, rowid_off, rowid_oid
 from .schema import CType, Schema, concat_batches, take_batch
@@ -91,6 +93,12 @@ def gather_payload(store: ObjectStore, schema: Schema,
     gather preserves input order, so the claim transfers to the batch) and
     is carried into the sidecar untouched. Never claim runs that aren't
     real — the seal path's order depends on it."""
+    with telemetry.span(SP_GATHER):
+        return _gather_payload(store, schema, rowids, with_sigs, runs)
+
+
+def _gather_payload(store: ObjectStore, schema: Schema, rowids: np.ndarray,
+                    with_sigs: bool, runs: Optional[np.ndarray]):
     n = rowids.shape[0]
     oids = rowid_oid(rowids)
     offs = rowid_off(rowids)

@@ -202,6 +202,7 @@ class Engine:
         if store.delta_cache is not None:
             store.delta_cache.hits = 0
         store.metrics.reset()
+        telemetry.PROCESS.reset()
         w = self.wal
         w.frames = w.bytes_written = w.fsyncs = 0
 

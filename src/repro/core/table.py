@@ -7,11 +7,16 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..kernels import ops
+from . import telemetry
 from .directory import Directory
 from .objects import DataObject, pack_rowid
 from .schema import CType, Schema, concat_batches, take_batch
 from .sigs import SigBatch
 from .visibility import visibility_index
+
+SP_LOCATE_KEYS = telemetry.register_span(
+    "table.locate_keys", "PK probe: zone-map pruning and one fused probe "
+    "per candidate object")
 
 
 class Table:
@@ -179,6 +184,11 @@ class Table:
         (ROADMAP §Performance); the merge planner's batches are run starts
         of key-sorted streams, so this is free for the hot callers.
         """
+        with telemetry.span(SP_LOCATE_KEYS):
+            return self._locate_keys(key_lo, key_hi, directory)
+
+    def _locate_keys(self, key_lo: np.ndarray, key_hi: np.ndarray,
+                     directory: Optional[Directory]) -> np.ndarray:
         d = directory or self.directory
         vi = visibility_index(self._store, d)
         q = key_lo.shape[0]

@@ -1,4 +1,4 @@
-"""Operation telemetry (ISSUE 8): spans + a unified metrics registry.
+"""Operation telemetry: spans + a unified metrics registry.
 
 The engine has accumulated a pile of disconnected counters —
 ``DeltaStats``, ``CommitStats``, ``GCStats``, the visibility-cache
@@ -16,7 +16,10 @@ unifies them:
   tracer records monotonic wall-time plus the delta of every registered
   counter across the span. Nesting follows the call stack (``diff`` →
   ``signed_delta`` → ``visibility.build``), so a span tree is a profile
-  of one operation with its costs attributed;
+  of one operation with its costs attributed. An armed span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+  taken meanwhile carries every span on the device trace's clock (the
+  benchmark charges device-idle time to the innermost open span);
 * **exports**: a text renderer for ``EXPLAIN`` (span tree + counter
   deltas, with zero-valued siblings of any touched counter group shown
   so invariants like ``commit.rows_rehashed=0`` are *visible*, not just
@@ -37,8 +40,17 @@ Two design rules keep telemetry out of the durability story:
 
 Cost when disarmed mirrors ``faults.crash_point``: ``span()`` is one
 global load + ``is None`` test returning a singleton no-op context
-manager. Spans mark *operations*, not rows — never open one inside a
-per-row loop (the interleaved A/B bench pins hot-path parity).
+manager (``tests/test_telemetry.py`` pins that it stays the singleton, and
+the benchmark's untraced runs measure the hot path with it in place).
+Spans mark *operations*, not rows — never open one inside a per-row loop.
+The kernel dispatch layer opens one ``ops.stage`` and one ``ops.fetch``
+span per device call.
+
+Counters with no owning engine object — the ``xfer.*`` transfer bytes of
+the kernel dispatch layer, which holds no store — live in one
+process-wide :class:`Metrics` bag, :data:`PROCESS`. ``metrics_snapshot``
+folds it in for any bound engine and ``Engine.reset_metrics`` zeroes it
+with the rest, so it reads like every other counter group.
 """
 from __future__ import annotations
 
@@ -52,13 +64,16 @@ __all__ = [
     "registered_metrics", "Metrics", "metrics_snapshot", "stats_json",
     "Span", "Tracer", "span", "trace", "current", "render_spans",
     "chrome_trace_events", "write_chrome_trace", "STATS_SCHEMA",
+    "PROCESS",
 ]
 
 #: version of the ``stats_json`` document (bumped on any key change, like
 #: the LINT report's ``schema: 1``). 2: the probe.* counter group
 #: (fused key probes + key-range shard plans, ISSUE 9). 3: the store.*
 #: counter group (tiered pack store + remotes, ISSUE 10).
-STATS_SCHEMA = 3
+#: 4: the xfer.* counter group (host-to-device bytes of the kernel
+#: dispatch layer).
+STATS_SCHEMA = 4
 
 #: span name -> human description. Populated at import time by the modules
 #: that own the operations, exactly like the crash-point registry.
@@ -138,6 +153,8 @@ for _n, _d in (
     ("store.bytes_packed", "pack-blob bytes freshly written to disk"),
     ("store.objects_pushed", "pack objects shipped to a remote by push"),
     ("store.objects_pulled", "pack objects fetched from a remote"),
+    ("xfer.h2d_bytes", "bytes copied host-to-device by the kernel dispatch "
+                       "layer, padding included"),
 ):
     register_metric(_n, _d)
 
@@ -159,6 +176,11 @@ class Metrics:
 
     def reset(self) -> None:
         self.counters.clear()
+
+
+#: the process-wide counter bag: counters of code that owns no engine
+#: object (``kernels/ops.py``'s ``xfer.*``)
+PROCESS = Metrics()
 
 
 def metrics_snapshot(engine) -> Dict[str, int]:
@@ -191,8 +213,9 @@ def metrics_snapshot(engine) -> Dict[str, int]:
     snap["wal.frames"] = w.frames
     snap["wal.bytes"] = w.bytes_written
     snap["wal.fsyncs"] = w.fsyncs
-    for name, v in store.metrics.counters.items():
-        snap[name] = v
+    for bag in (store.metrics, PROCESS):
+        for name, v in bag.counters.items():
+            snap[name] = v
     return snap
 
 
@@ -210,13 +233,14 @@ class Span:
     """One timed operation: monotonic duration + counter deltas + children.
 
     Created armed-path only (``span()`` returns the no-op singleton when
-    no tracer is active). Counter deltas are ``snapshot_at_exit -
-    snapshot_at_enter`` over the union of keys, so a tracer whose engine
-    was bound mid-flight (the CLI arms before the store loads) still
-    renders — pre-bind baselines are simply all zeros."""
+    no tracer is active). While open it is also a profiler annotation of
+    the same name; ``dur_s`` stays on ``perf_counter``. Counter deltas are
+    ``snapshot_at_exit - snapshot_at_enter`` over the union of keys, so a
+    tracer whose engine was bound mid-flight (the CLI arms before the
+    store loads) still renders — pre-bind baselines are simply all zeros."""
 
     __slots__ = ("name", "tracer", "t0_rel", "dur_s", "counters",
-                 "children", "_base", "_t0")
+                 "children", "_base", "_t0", "_note")
 
     def __init__(self, name: str, tracer: "Tracer"):
         self.name = name
@@ -232,12 +256,15 @@ class Span:
         (parent.children if parent is not None else tr.roots).append(self)
         tr._stack.append(self)
         self._base = metrics_snapshot(tr.engine)
+        self._note = tr._annotation(self.name)
+        self._note.__enter__()
         self._t0 = perf_counter()
         self.t0_rel = self._t0 - tr.t0
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur_s = perf_counter() - self._t0
+        self._note.__exit__(*exc)
         base = self._base
         now = metrics_snapshot(self.tracer.engine)
         deltas = {}
@@ -289,6 +316,10 @@ class Tracer:
     :meth:`bind` once the engine exists."""
 
     def __init__(self, engine=None):
+        # imported on the armed path only: a disarmed process never loads
+        # the profiler for telemetry's sake
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.engine = engine
         self.roots: List[Span] = []
         self._stack: List[Span] = []

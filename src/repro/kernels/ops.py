@@ -10,6 +10,13 @@ only) routes everything through the device path on the CPU, with the
 Pallas kernels in interpret mode. There is no fallback: on a TPU the
 device path is the only path, and its errors propagate.
 
+Each device call is traced as two telemetry spans: ``ops.stage`` (padding,
+lane unpacking, the host-to-device copies and the program's dispatch) and
+``ops.fetch`` (waiting for the result, the device-to-host copy, slicing
+off the padding). The bytes each call copies to the device, padding
+included, count in ``xfer.h2d_bytes`` (``telemetry.PROCESS``). The numpy
+paths open no span and count nothing.
+
 Signature convention: a 64-bit word is carried host-side as numpy uint64;
 kernels see it as (hi32, lo32) uint32 lanes. A row signature is 128 bits =
 two uint64 words (lo64, hi64); sorting is lexicographic by (hi64, lo64) --
@@ -22,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..core import telemetry
 from . import ref
 from .rowhash import rowhash_pallas, DEFAULT_BLOCK_ROWS
 from .search import lower_bound_lanes, probe_lanes
@@ -38,6 +46,14 @@ _ALL_ONES = np.uint64(0xFFFFFFFFFFFFFFFF)
 #: large the batch (a whole table load hashes in one ``rowhash`` call)
 ROWHASH_CHUNK = 1 << 18
 
+SP_STAGE = telemetry.register_span(
+    "ops.stage", "device call: pad and unpack the inputs, copy them to the "
+    "device, dispatch the program")
+SP_FETCH = telemetry.register_span(
+    "ops.fetch", "device call: wait for the result, copy it to the host, "
+    "slice off the padding")
+XFER_H2D = "xfer.h2d_bytes"
+
 
 def backend_uses_pallas() -> bool:
     return FORCE_PALLAS_INTERPRET or jax.default_backend() == "tpu"
@@ -47,10 +63,16 @@ def _interp() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _to_device(*arrays: np.ndarray):
+    """Host arrays -> device arrays, counting the bytes sent."""
+    telemetry.PROCESS.add(XFER_H2D, sum(a.nbytes for a in arrays))
+    return tuple(jnp.asarray(a) for a in arrays)
+
+
 def _device_lanes(*words: np.ndarray):
     """uint64 words -> one tuple of device uint32 lanes, each word as
     (hi32, lo32), most significant lane first."""
-    return tuple(jnp.asarray(lane) for w in words for lane in unpack64(w))
+    return _to_device(*(lane for w in words for lane in unpack64(w)))
 
 
 def _pow2(n: int, least: int = 1) -> int:
@@ -111,10 +133,13 @@ def rowhash(lanes_u32: np.ndarray) -> np.ndarray:
         for s in range(0, r, ROWHASH_CHUNK):
             part = lanes[s:s + ROWHASH_CHUNK]
             k = part.shape[0]
-            cols = np.ascontiguousarray(
-                _pad_rows(part, DEFAULT_BLOCK_ROWS).T).reshape(c, -1, LANES)
-            res = rowhash_pallas(jnp.asarray(cols), interpret=_interp())
-            out[s:s + k] = np.asarray(res).reshape(4, -1)[:, :k].T
+            with telemetry.span(SP_STAGE):
+                cols = np.ascontiguousarray(
+                    _pad_rows(part, DEFAULT_BLOCK_ROWS).T).reshape(
+                        c, -1, LANES)
+                res = rowhash_pallas(*_to_device(cols), interpret=_interp())
+            with telemetry.span(SP_FETCH):
+                out[s:s + k] = np.asarray(res).reshape(4, -1)[:, :k].T
         return out
     # CPU fast path: identical math in numpy (wrapping uint32).
     return _rowhash_np(np.asarray(lanes_u32, np.uint32))
@@ -163,9 +188,11 @@ def lower_bound(sorted_u64: np.ndarray, queries_u64: np.ndarray) -> np.ndarray:
         return np.zeros(queries_u64.shape, np.int64)
     if backend_uses_pallas():
         nq = queries_u64.shape[0]
-        idx = lower_bound_lanes(_search_table(sorted_u64),
-                                _search_queries(queries_u64))
-        return np.asarray(idx)[:nq].astype(np.int64)
+        with telemetry.span(SP_STAGE):
+            idx = lower_bound_lanes(_search_table(sorted_u64),
+                                    _search_queries(queries_u64))
+        with telemetry.span(SP_FETCH):
+            return np.asarray(idx)[:nq].astype(np.int64)
     return np.searchsorted(sorted_u64, queries_u64, side="left").astype(np.int64)
 
 
@@ -245,10 +272,12 @@ def probe128(t_lo: np.ndarray, t_hi: np.ndarray,
     if nq == 0 or n == 0:
         return np.zeros((nq,), np.int64), np.zeros((nq,), np.int64)
     if backend_uses_pallas():
-        start, ub = probe_lanes(_search_table(t_lo, t_hi),
-                                _search_queries(q_lo, q_hi))
-        start = np.asarray(start)[:nq].astype(np.int64)
-        ub = np.minimum(np.asarray(ub)[:nq], n).astype(np.int64)
+        with telemetry.span(SP_STAGE):
+            start, ub = probe_lanes(_search_table(t_lo, t_hi),
+                                    _search_queries(q_lo, q_hi))
+        with telemetry.span(SP_FETCH):
+            start = np.asarray(start)[:nq].astype(np.int64)
+            ub = np.minimum(np.asarray(ub)[:nq], n).astype(np.int64)
         return start, ub - start
     # CPU fused fast path: one primary-word searchsorted for everything
     lb = np.searchsorted(t_lo, q_lo, side="left").astype(np.int64)
@@ -589,11 +618,13 @@ def _segsum_boundary(s_lo: np.ndarray, s_hi: np.ndarray) -> np.ndarray:
     rows = -(-n // LANES)
     block_rows = min(SEG_BLOCK_ROWS, -(-rows // 8) * 8)
     block = block_rows * LANES
-    lanes = [_pad_rows(x, block).reshape(-1, LANES)
-             for w in (s_lo, s_hi) for x in unpack64(w)]
-    flags = boundary_pallas(*(jnp.asarray(x) for x in lanes),
-                            block_rows=block_rows, interpret=_interp())
-    bnd = np.asarray(flags).reshape(-1)[:n] != 0
+    with telemetry.span(SP_STAGE):
+        lanes = [_pad_rows(x, block).reshape(-1, LANES)
+                 for w in (s_lo, s_hi) for x in unpack64(w)]
+        flags = boundary_pallas(*_to_device(*lanes),
+                                block_rows=block_rows, interpret=_interp())
+    with telemetry.span(SP_FETCH):
+        bnd = np.asarray(flags).reshape(-1)[:n] != 0
     # a block's first element has its predecessor in the previous block
     first = np.arange(block, n, block)
     bnd[first] = ((s_lo[first] != s_lo[first - 1])

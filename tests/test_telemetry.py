@@ -64,6 +64,7 @@ PINNED_METRICS = [
     "wal.bytes",
     "wal.frames",
     "wal.fsyncs",
+    "xfer.h2d_bytes",
 ]
 
 
@@ -125,7 +126,7 @@ def test_stats_json_golden_schema():
     repo = _mk_repo()
     doc = telemetry.stats_json(repo.engine)
     assert set(doc) == {"schema", "metrics"}
-    assert doc["schema"] == telemetry.STATS_SCHEMA == 3
+    assert doc["schema"] == telemetry.STATS_SCHEMA == 4
     assert list(doc["metrics"]) == PINNED_METRICS  # sorted AND complete
     # engine=None (CLI arms before the store loads): same keys, all zero
     empty = telemetry.stats_json(None)
@@ -179,7 +180,7 @@ def test_workflow_e2e_span_tree():
     assert _names(commit.children) == ["commit.seal", "commit.swing"]
     assert _names(publish.children) == ["plan_merge", "commit"]
     plan, pcommit = publish.children
-    assert set(_names(plan.children)) == {"signed_delta"}
+    assert set(_names(plan.children)) == {"signed_delta", "gather_payload"}
     assert _names(pcommit.children) == ["commit.seal", "commit.swing"]
     assert pcommit.counters["commit.rows_carried"] > 0
     assert pcommit.counters.get("commit.rows_rehashed", 0) == 0
