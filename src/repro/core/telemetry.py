@@ -72,8 +72,9 @@ __all__ = [
 #: (fused key probes + key-range shard plans, ISSUE 9). 3: the store.*
 #: counter group (tiered pack store + remotes, ISSUE 10).
 #: 4: the xfer.* counter group (host-to-device bytes of the kernel
-#: dispatch layer).
-STATS_SCHEMA = 4
+#: dispatch layer). 5: the ops.ranksum_* counters (runs and calls of the
+#: device rank-sum merge).
+STATS_SCHEMA = 5
 
 #: span name -> human description. Populated at import time by the modules
 #: that own the operations, exactly like the crash-point registry.
@@ -155,6 +156,9 @@ for _n, _d in (
     ("store.objects_pulled", "pack objects fetched from a remote"),
     ("xfer.h2d_bytes", "bytes copied host-to-device by the kernel dispatch "
                        "layer, padding included"),
+    ("ops.ranksum_runs", "presorted runs merged by the device rank-sum merge"),
+    ("ops.ranksum_calls", "searchsorted128 calls issued by the device "
+                          "rank-sum merge"),
 ):
     register_metric(_n, _d)
 

@@ -15,7 +15,9 @@ lane unpacking, the host-to-device copies and the program's dispatch) and
 ``ops.fetch`` (waiting for the result, the device-to-host copy, slicing
 off the padding). The bytes each call copies to the device, padding
 included, count in ``xfer.h2d_bytes`` (``telemetry.PROCESS``). The numpy
-paths open no span and count nothing.
+paths open no span and count nothing. Each rank-sum run merge counts its
+runs in ``ops.ranksum_runs`` and its ``searchsorted128`` calls in
+``ops.ranksum_calls``.
 
 Signature convention: a 64-bit word is carried host-side as numpy uint64;
 kernels see it as (hi32, lo32) uint32 lanes. A row signature is 128 bits =
@@ -53,6 +55,8 @@ SP_FETCH = telemetry.register_span(
     "ops.fetch", "device call: wait for the result, copy it to the host, "
     "slice off the padding")
 XFER_H2D = "xfer.h2d_bytes"
+RANKSUM_RUNS = "ops.ranksum_runs"
+RANKSUM_CALLS = "ops.ranksum_calls"
 
 
 def backend_uses_pallas() -> bool:
@@ -458,10 +462,11 @@ def merge128_runs(lo: np.ndarray, hi: np.ndarray,
     by key range and CPU gets cache-sized partitions for free.
 
     Backend dispatch: on the device backend the runs are merged by
-    searchsorted rank-sums (k passes of the device lower bound, no sort at
-    all); on CPU the run-aware stable argsort is measurably faster (timsort's
-    galloping merge on run-structured input: ~4ms vs ~40ms per 200k rows x 9
-    runs), so the rank-sum path is reserved for the kernel backend."""
+    searchsorted rank-sums (2(k-1) calls of the device lower bound, no sort
+    at all; see ``_merge128_ranksum``); on CPU the run-aware stable argsort
+    is measurably faster (timsort's galloping merge on run-structured
+    input: ~4ms vs ~40ms per 200k rows x 9 runs), so the rank-sum path is
+    reserved for the kernel backend."""
     n = lo.shape[0]
     starts = np.asarray(starts, np.int64)
     if n == 0 or starts.shape[0] <= 1:
@@ -522,22 +527,35 @@ def _merge128_ranksum(lo: np.ndarray, hi: np.ndarray,
                       starts: np.ndarray) -> np.ndarray:
     """k-way merge by rank sums: each element's merged position is its
     in-run rank plus, per other run, the count of elements that must precede
-    it (strictly-less, or less-or-equal for earlier runs — that tie-break
-    makes the merge stable)."""
+    it (less-or-equal for earlier runs, strictly-less for later runs — that
+    tie-break makes the merge stable).
+
+    Each run serves as the search table twice: once for all later runs
+    together (one contiguous query slice, ``side="right"``) and once for all
+    earlier runs together (``side="left"``), so a merge of k runs issues
+    2(k-1) ``searchsorted128`` calls. The device descent is fixed-depth, so
+    the queries need not arrive sorted."""
     n = lo.shape[0]
     bounds = np.append(starts, n)
     k = starts.shape[0]
-    dest = np.empty((n,), np.int64)
-    for r in range(k):
-        s, e = int(bounds[r]), int(bounds[r + 1])
-        d = np.arange(e - s, dtype=np.int64)
-        for q in range(k):
-            if q == r:
-                continue
-            qs, qe = int(bounds[q]), int(bounds[q + 1])
-            d += searchsorted128(lo[qs:qe], hi[qs:qe], lo[s:e], hi[s:e],
-                                 side="right" if q < r else "left")
-        dest[s:e] = d
+    # in-run rank of every element
+    dest = np.arange(n, dtype=np.int64) - np.repeat(starts, np.diff(bounds))
+    calls = 0
+    for q in range(k):
+        qs, qe = int(bounds[q]), int(bounds[q + 1])
+        if qe == qs:
+            continue
+        t_lo, t_hi = lo[qs:qe], hi[qs:qe]
+        if qe < n:
+            dest[qe:] += searchsorted128(t_lo, t_hi, lo[qe:], hi[qe:],
+                                         side="right")
+            calls += 1
+        if qs > 0:
+            dest[:qs] += searchsorted128(t_lo, t_hi, lo[:qs], hi[:qs],
+                                         side="left")
+            calls += 1
+    telemetry.PROCESS.add(RANKSUM_RUNS, k)
+    telemetry.PROCESS.add(RANKSUM_CALLS, calls)
     order = np.empty((n,), np.int64)
     order[dest] = np.arange(n, dtype=np.int64)
     return order

@@ -24,6 +24,7 @@ except ImportError:  # pragma: no cover
 
 from repro.core import (Column, ConflictMode, CType, Engine, Schema,
                         three_way_merge)
+from repro.core import telemetry
 from repro.core.delta import SignedStream
 from repro.core.indices import create_index, lookup_eq
 from repro.core.sigs import key_sigs_for_lookup
@@ -151,6 +152,78 @@ def test_kway_merge_lo64_collisions():
         t_lo.astype(object) * (1 << 64) + t_hi.astype(object), int(l) * (1 << 64) + int(h))
         for l, h in zip(lo[q], hi[q])], np.int64)
     np.testing.assert_array_equal(pos, want)
+
+
+@pytest.fixture
+def device_path(monkeypatch):
+    monkeypatch.setattr(ops, "FORCE_PALLAS_INTERPRET", True)
+
+
+def _shared_key_runs(rng, k, collide):
+    """k non-empty presorted runs; about a third of each later run's keys
+    are copied from the run before it, so equal keys meet across runs (a
+    row deleted in one run and re-added in the next). Within a run the lo
+    words are distinct unless ``collide``, which draws them from a domain
+    of 3 so that lo64 collisions fill every run."""
+    runs, prev = [], None
+    for _ in range(k):
+        m = int(rng.integers(20, 90))
+        lo = (rng.integers(0, 3, m) if collide else
+              rng.choice(1 << 40, m, replace=False)).astype(np.uint64)
+        hi = rng.integers(0, 1 << 62, m).astype(np.uint64)
+        if prev is not None:
+            c = min(m // 3, prev[0].shape[0])
+            take = rng.choice(prev[0].shape[0], c, replace=False)
+            lo[:c], hi[:c] = prev[0][take], prev[1][take]
+            if not collide:   # keep the run's own lo words distinct
+                keep = np.unique(lo, return_index=True)[1]
+                lo, hi = lo[keep], hi[keep]
+        o = np.lexsort((hi, lo))
+        prev = (lo[o], hi[o])
+        runs.append(list(zip(prev[0].tolist(), prev[1].tolist())))
+    return runs
+
+
+def _count_lower_bound_calls(monkeypatch):
+    """Wrap ``ops.lower_bound`` the way the benchmark's kernel-work
+    recorder does: one count per non-empty call."""
+    calls = []
+    inner = ops.lower_bound
+
+    def counted(t, q):
+        if t.shape[0] and q.shape[0]:
+            calls.append(q.shape[0])
+        return inner(t, q)
+    monkeypatch.setattr(ops, "lower_bound", counted)
+    return calls
+
+
+@pytest.mark.parametrize("k", [1, 2, 5, 24])
+@pytest.mark.parametrize("collide", [False, True], ids=["distinct", "lo64"])
+def test_ranksum_merge_searches_once_per_run_and_side(
+        device_path, monkeypatch, k, collide):
+    """The device rank-sum merge equals the lexsort oracle under cross-run
+    equal keys, and with distinct lo words in each run it makes exactly
+    2(k-1) device lower-bound calls: one per run and side."""
+    rng = np.random.default_rng([k, collide] + list(b"RANKSUM"))
+    lo, hi, starts = _flatten(_shared_key_runs(rng, k, collide))
+    calls = _count_lower_bound_calls(monkeypatch)
+    np.testing.assert_array_equal(ops._merge128_ranksum(lo, hi, starts),
+                                  _oracle(lo, hi))
+    if not collide:
+        assert len(calls) == 2 * (k - 1)
+
+
+def test_ranksum_merge_counts_runs_and_calls(device_path):
+    rng = np.random.default_rng(23)
+    k = 5
+    lo, hi, starts = _flatten(_shared_key_runs(rng, k, collide=False))
+    before = dict(telemetry.PROCESS.counters)
+    order = ops.merge128_runs(lo, hi, starts)
+    np.testing.assert_array_equal(order, _oracle(lo, hi))
+    got = {name: telemetry.PROCESS.counters.get(name, 0) - before.get(name, 0)
+           for name in ("ops.ranksum_runs", "ops.ranksum_calls")}
+    assert got == {"ops.ranksum_runs": k, "ops.ranksum_calls": 2 * (k - 1)}
 
 
 def test_sort128_radix_fallback_large_unsorted():
