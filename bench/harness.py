@@ -2,12 +2,18 @@
 
 Everything particular to a cell is found by name:
 
-- its configuration: the ``file`` of its ``configs`` entry (JSON);
+- its configuration: the ``file`` of its ``configs`` entry (JSON), which
+  names its generator, ``bench/<generator>.py``, and its plain reference,
+  ``bench/reference/<reference>.py`` (``bench/workload.py``);
 - its traffic mix: ``bench/traffic/<traffic>.json``, read by the one
-  generator (``bench/traffic.py``) and driven by the runner its ``kind``
-  names (``bench/workload.py``);
+  traffic generator (``bench/traffic.py``) and driven by the runner its
+  ``kind`` names, ``bench/runners/<kind>.py``, which loads the
+  configuration itself;
 - each per-layer metric: ``bench/metrics/<name>.py``, whose ``read(ctx)``
   returns the number or None when it finds nothing to read.
+
+So a new configuration, kind of mix, cell or metric is new files and
+entries alone.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics; with
 ``--trace 1`` its per-layer metrics, read from the telemetry spans and
@@ -16,7 +22,6 @@ counters of the window, a profiler trace of it, and the kernels' work.
 from __future__ import annotations
 
 import contextlib
-import importlib.util
 import json
 import os
 import shutil
@@ -26,7 +31,7 @@ from typing import Callable, Dict, Optional
 
 from . import trace as trace_mod
 from . import work
-from .workload import RUNNERS, Loaded, Verbs, log
+from .workload import Verbs, load_module, log
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -74,12 +79,17 @@ def applies(metric: dict, workload: str) -> bool:
 
 def reader_of(name: str, root: str = ROOT) -> Callable:
     """``read`` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(os.path.join(root, "bench", "metrics",
+                                    name + ".py")).read
+
+
+def runner_of(kind: str, root: str = ROOT) -> Callable:
+    """``RUNNER`` of ``bench/runners/<kind>.py``: called with the cell's
+    configuration, mix, seed, verbs and root, it loads the configuration
+    and gives ``repo``, ``warm_up()``, ``window(seconds)``,
+    ``end_to_end(window_s)``, ``counts()`` and ``check(lower_precision)``."""
+    return load_module(os.path.join(root, "bench", "runners",
+                                    kind + ".py")).RUNNER
 
 
 # --------------------------------------------------------- what readers see
@@ -235,8 +245,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     jax.monitoring.register_event_listener(compiles.on_event)
 
     verbs = Verbs()
-    loaded = Loaded(config, seed)
-    runner = RUNNERS[mix["kind"]](loaded, mix, seed, verbs)
+    runner = runner_of(mix["kind"], root)(config, mix, seed, verbs, root)
     runner.warm_up()
     verbs.reset()
     setup_s = time.perf_counter() - t_start
@@ -244,7 +253,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
         f"{compiles.loaded} of them loaded from the persistent cache")
 
     from repro.core import telemetry
-    engine = loaded.repo.engine
+    engine = runner.repo.engine
     kernel_work = KernelWork()
     trace_dir = os.path.join(root, ".bench_trace", f"{workload}-{seed}")
     c0 = compiles.n

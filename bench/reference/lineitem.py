@@ -3,8 +3,9 @@ arrays, kept from the generator's rows and the traffic's updates alone.
 
 It imports nothing of the program. The harness hands it the rows the
 generator made before the program saw them, and the updates the traffic
-drew; from those it says what each diff, the table after each publish and
-each point read must hold, and counts the rows of an answer that differ.
+drew; from those it says what each diff, the table after each publish,
+each publish's true conflicts and each point read must hold, and counts
+the rows of an answer that differ.
 Rows are compared value for value, every column, never by signature.
 
 The control (``lower_precision``) is this reference with every float64
@@ -13,7 +14,7 @@ configuration states. It must fail the comparison.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Sequence
+from typing import Dict, Iterable, List, Sequence
 
 import numpy as np
 
@@ -35,11 +36,37 @@ def updated(base: Rows, idx: np.ndarray, changes: Rows) -> Rows:
 
 
 def table_after(base: Rows, updates: Iterable[tuple]) -> Rows:
-    """``base`` with each ``(idx, changes)`` update applied in turn."""
+    """``base`` with each ``(idx, changes)`` update applied in turn, in the
+    order the PRs are published. Where two updates change one row the
+    later one's version stands: the ACCEPT rule, under which a publish
+    forces its own version over a true conflict. With disjoint updates
+    every conflict mode gives this table."""
     out = {c: v.copy() for c, v in base.items()}
     for idx, changes in updates:
         for c, v in changes.items():
             out[c][idx] = v
+    return out
+
+
+def true_conflicts(base: Rows, updates: Iterable[tuple]) -> List[int]:
+    """The true conflicts of each update when the updates are published in
+    turn: rows of its change set that an earlier update also changed,
+    counted only where the two new versions differ in some column (equal
+    new versions cancel)."""
+    out = []
+    seen_idx = np.zeros(0, np.int64)
+    seen = {c: v[:0] for c, v in base.items()}
+    for idx, changes in updates:
+        new = updated(base, idx, changes)
+        _, si, ni = np.intersect1d(seen_idx, idx, return_indices=True)
+        differ = np.zeros(si.shape[0], bool)
+        for c in new:
+            differ |= seen[c][si] != new[c][ni]
+        out.append(int(differ.sum()))
+        keep = np.ones(seen_idx.shape[0], bool)
+        keep[si] = False          # the later version replaces the earlier
+        seen_idx = np.concatenate([seen_idx[keep], idx])
+        seen = {c: np.concatenate([seen[c][keep], new[c]]) for c in new}
     return out
 
 

@@ -16,13 +16,16 @@ SMALL_CHANGES = 50
 def make_small_root(dst: str, src: str = harness.ROOT) -> str:
     os.makedirs(os.path.join(dst, "bench"), exist_ok=True)
     shutil.copy(os.path.join(src, "BENCHMARK.json"), dst)
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "runners", "reference"):
         shutil.copytree(os.path.join(src, "bench", d),
-                        os.path.join(dst, "bench", d), dirs_exist_ok=True)
+                        os.path.join(dst, "bench", d), dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for name in os.listdir(os.path.join(dst, "bench", "configs")):
         path = os.path.join(dst, "bench", "configs", name)
         with open(path) as f:
             config = json.load(f)
+        shutil.copy(os.path.join(src, "bench", config["generator"] + ".py"),
+                    os.path.join(dst, "bench"))
         config["scale_factor"] = SMALL_SCALE
         with open(path, "w") as f:
             json.dump(config, f)

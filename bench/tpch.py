@@ -7,6 +7,10 @@ stores them: decimals as float64, dates as int64 day numbers since
 text columns as object arrays of ``bytes``. Rows are in load order, sorted
 by (l_orderkey, l_linenumber).
 
+As a configuration's ``generator`` (``"generator": "tpch"``) it gives the
+columns of the configuration's one table (``columns``) and its rows and
+text pool from the seed (``generate``).
+
 Departures, also listed under ``assumed`` in each configuration file:
 
 - ``l_suppkey`` is drawn uniformly from [1, SF * 10,000], not by the
@@ -171,3 +175,17 @@ def lineitem(scale_factor: float, seed: int
         "l_comment": comments(rng, pool, n),
     }
     return rows, pool
+
+
+def columns(config: dict) -> Dict[str, tuple]:
+    """The columns of each table the configuration names: its one table,
+    LINEITEM."""
+    return {config["table"]: COLUMNS}
+
+
+def generate(config: dict, seed: int
+             ) -> Tuple[Dict[str, Dict[str, np.ndarray]], bytes]:
+    """The rows of each table of the configuration, by table name, and the
+    text pool that updates draw new comments from."""
+    rows, pool = lineitem(float(config["scale_factor"]), seed)
+    return {config["table"]: rows}, pool

@@ -28,12 +28,25 @@ FNV_PRIME = np.uint64(1099511628211)
 def round_updates(rows: Dict[str, np.ndarray], pool: bytes, mix: dict,
                   seed: int, rnd: int) -> List[Update]:
     """One collaborative round: per engineer, ``rows_per_engineer`` distinct
-    rows drawn uniformly, disjoint from every other engineer's, and their
-    new ``l_quantity`` and ``l_comment``."""
+    rows drawn uniformly, and their new ``l_quantity`` and ``l_comment``.
+
+    With the mix's ``overlap`` (a fraction, 0 where absent), each engineer
+    after the first shares ``k = round(overlap * rows_per_engineer)`` rows
+    with the one before it: the first ``k`` rows of its draw are replaced by
+    rows ``k .. 2k-1`` of the previous engineer's, which that engineer
+    keeps. So no row is in three change sets; without overlap the change
+    sets are disjoint. The replacement draws nothing, so the new values
+    come from the same stream either way."""
     rng = np.random.default_rng([seed, 0xC0, rnd])
     n = rows["l_orderkey"].shape[0]
     e, m = int(mix["engineers"]), int(mix["rows_per_engineer"])
+    k = int(round(float(mix.get("overlap", 0)) * m))
+    if 2 * k > m:
+        raise ValueError(f"overlap {mix['overlap']} shares more than half "
+                         "of an engineer's rows")
     pick = rng.choice(n, size=e * m, replace=False)
+    for w in range(1, e):
+        pick[w * m:w * m + k] = pick[(w - 1) * m + k:(w - 1) * m + 2 * k]
     out = []
     for w in range(e):
         idx = np.sort(pick[w * m:(w + 1) * m])
