@@ -615,19 +615,13 @@ class Engine:
             batch = None  # one rebuild scan shared by every rebuilt index
             for spec in self.indices.get(snap.table, []):
                 new_spec = IndexSpec(spec.name, new_name, spec.columns)
-                aux_t = self.tables.get(spec.aux_table)
-                aux_dir = None
-                if aux_t is not None:
-                    try:
-                        aux_dir = aux_t.directory_at(horizon)
-                    except KeyError:
-                        pass  # index younger than the snapshot
+                aux_dir = self._aux_version(spec, horizon)
                 if aux_dir is not None:
                     self.clone_table(
                         new_spec.aux_table,
                         Snapshot(name=None, table=spec.aux_table,
-                                 schema=aux_t.schema, directory=aux_dir,
-                                 created_ts=horizon),
+                                 schema=self.tables[spec.aux_table].schema,
+                                 directory=aux_dir, created_ts=horizon),
                         _log=False)
                 else:
                     batch = backfill_index(self, new_spec, batch)
@@ -641,7 +635,11 @@ class Engine:
         """RESTORE TABLE table FROM SNAPSHOT src — git reset --hard.
 
         ``src`` may be any ref form; table-relative refs (ts:N, HEAD, ~n)
-        resolve against ``table``."""
+        resolve against ``table``. The table's secondary indices are
+        restored with it, in the same (one-record) operation: each aux
+        table goes back to its version at the snapshot's horizon, as
+        ``clone_table(with_indices=True)`` picks it, or is rebuilt from the
+        restored rows where that version is gone."""
         t: Table = require(self.tables, table, "table")
         snap = self._snapshotish(src, table=table)
         if snap.table != table and not t.schema.compatible_with(snap.schema):
@@ -649,11 +647,45 @@ class Engine:
         t.schema = snap.schema  # PITR across schema change (paper §5.5.6)
         t.set_directory(Directory(snap.directory.data_oids,
                                   snap.directory.tomb_oids, snap.ts))
+        self._restore_indices(table, snap)
         self.commit_log.append(CommitRecord(self.ts, table, "restore", 0, 0))
         if snap.table != table:
             self.set_common_base(table, snap.table, snap)
         if _log:
             self.wal.append("restore", table=table, snap=snap)
+
+    def _restore_indices(self, table: str, snap: Snapshot) -> None:
+        """Point each index of ``table`` at its aux version consistent with
+        ``snap`` (the snapshot's table's index of the same name and
+        columns, at the snapshot's horizon); rebuild it from ``table``'s
+        restored rows where there is no such version."""
+        specs = self.indices.get(table, [])
+        if not specs:
+            return
+        from .indices import backfill_index
+        horizon = max(snap.created_ts, snap.directory.ts)
+        src = {(s.name, s.columns): s for s in self.indices.get(snap.table, [])}
+        batch = None  # one rebuild scan shared by every rebuilt index
+        for spec in specs:
+            s = src.get((spec.name, spec.columns))
+            aux_dir = None if s is None else self._aux_version(s, horizon)
+            if aux_dir is not None:
+                self.tables[spec.aux_table].set_directory(aux_dir)
+            else:
+                self.drop_table(spec.aux_table, _log=False)
+                batch = backfill_index(self, spec, batch)
+
+    def _aux_version(self, spec, horizon: int) -> Optional[Directory]:
+        """The directory of ``spec``'s aux table at ``horizon`` (PITR), or
+        None where the index is younger than the horizon or its history was
+        trimmed past it."""
+        aux_t = self.tables.get(spec.aux_table)
+        if aux_t is None:
+            return None
+        try:
+            return aux_t.directory_at(horizon)
+        except KeyError:
+            return None
 
     # ------------------------------------------------------ schema change
     def alter_table_add_column(self, table: str, column, default, *,

@@ -73,8 +73,9 @@ __all__ = [
 #: counter group (tiered pack store + remotes, ISSUE 10).
 #: 4: the xfer.* counter group (host-to-device bytes of the kernel
 #: dispatch layer). 5: the ops.ranksum_* counters (runs and calls of the
-#: device rank-sum merge).
-STATS_SCHEMA = 5
+#: device rank-sum merge). 6: the index.* counters (values looked up and
+#: aux rows maintained by secondary indices).
+STATS_SCHEMA = 6
 
 #: span name -> human description. Populated at import time by the modules
 #: that own the operations, exactly like the crash-point registry.
@@ -159,6 +160,9 @@ for _n, _d in (
     ("ops.ranksum_runs", "presorted runs merged by the device rank-sum merge"),
     ("ops.ranksum_calls", "searchsorted128 calls issued by the device "
                           "rank-sum merge"),
+    ("index.lookup_values", "values looked up through secondary indices"),
+    ("index.aux_rows", "index aux-table rows deleted and inserted by "
+                       "maintenance in commits"),
 ):
     register_metric(_n, _d)
 

@@ -152,7 +152,10 @@ def resolve_branch(engine, name: Optional[str]) -> Branch:
 def create_branch(engine, name: str, tables: Sequence[str],
                   from_ref: Optional[str] = None, *, _log=True) -> Branch:
     """Clone ``tables`` under the ``name/`` namespace in one WAL-logged
-    operation, recording the branch-point snapshot per table."""
+    operation, recording the branch-point snapshot per table. Each clone
+    carries its table's secondary indices at the branch point, so the
+    branch's own transactions maintain them and lookups on the branch see
+    the branch; ``drop_branch`` drops them with the tables."""
     if _log:
         # user-facing creations only — replay must load pre-grammar names
         validate_name(name, "branch name")
@@ -181,7 +184,7 @@ def create_branch(engine, name: str, tables: Sequence[str],
     for lg in tables:
         snap = engine.current_snapshot(src[lg])
         phys = branch_table_name(name, lg)
-        engine.clone_table(phys, snap, _log=False)
+        engine.clone_table(phys, snap, with_indices=True, _log=False)
         mapping[lg] = phys
         bases[lg] = snap
     br = Branch(name, mapping, bases, parent, engine.ts)

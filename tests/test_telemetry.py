@@ -44,6 +44,8 @@ PINNED_METRICS = [
     "gc.objects_freed",
     "gc.pinned_horizons",
     "gc.versions_pruned",
+    "index.aux_rows",
+    "index.lookup_values",
     "ops.ranksum_calls",
     "ops.ranksum_runs",
     "probe.expansions",
@@ -128,7 +130,7 @@ def test_stats_json_golden_schema():
     repo = _mk_repo()
     doc = telemetry.stats_json(repo.engine)
     assert set(doc) == {"schema", "metrics"}
-    assert doc["schema"] == telemetry.STATS_SCHEMA == 5
+    assert doc["schema"] == telemetry.STATS_SCHEMA == 6
     assert list(doc["metrics"]) == PINNED_METRICS  # sorted AND complete
     # engine=None (CLI arms before the store loads): same keys, all zero
     empty = telemetry.stats_json(None)
