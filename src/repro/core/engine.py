@@ -130,6 +130,24 @@ class Txn:
         self.insert(table, batch)
         return n
 
+    def staged_edits(self, table: str) -> Tuple[list, list, list]:
+        """Copies of the lists ``table``'s inserts, their signature
+        sidecars and its delete rowids are staged in (the arrays shared)."""
+        return (list(self._ins.get(table, ())),
+                list(self._sigs.get(table, ())),
+                list(self._del.get(table, ())))
+
+    def restage(self, table: str, edits: Tuple[list, list, list]) -> None:
+        """Stage on ``table`` what ``staged_edits`` took from another
+        transaction, as it is: its batches are already normalized, so they
+        are not normalized twice."""
+        ins, sigs, dels = edits
+        if ins:
+            self._ins.setdefault(table, []).extend(ins)
+            self._sigs.setdefault(table, []).extend(sigs)
+        if dels:
+            self._del.setdefault(table, []).extend(dels)
+
     @property
     def staged(self) -> bool:
         """True iff the workspace holds any insert or delete."""

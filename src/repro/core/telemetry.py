@@ -74,8 +74,9 @@ __all__ = [
 #: 4: the xfer.* counter group (host-to-device bytes of the kernel
 #: dispatch layer). 5: the ops.ranksum_* counters (runs and calls of the
 #: device rank-sum merge). 6: the index.* counters (values looked up and
-#: aux rows maintained by secondary indices).
-STATS_SCHEMA = 6
+#: aux rows maintained by secondary indices). 7: the publish.* counters
+#: (merge plans publish reused from the checks' preview or planned again).
+STATS_SCHEMA = 7
 
 #: span name -> human description. Populated at import time by the modules
 #: that own the operations, exactly like the crash-point registry.
@@ -163,6 +164,11 @@ for _n, _d in (
     ("index.lookup_values", "values looked up through secondary indices"),
     ("index.aux_rows", "index aux-table rows deleted and inserted by "
                        "maintenance in commits"),
+    ("publish.plans_reused", "tables whose merge plan, made for the CI "
+                             "checks' preview, publish committed as it was"),
+    ("publish.plans_replanned", "tables publish planned a second time "
+                                "because the base, head or merge base moved "
+                                "after the preview"),
 ):
     register_metric(_n, _d)
 

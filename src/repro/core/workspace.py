@@ -17,13 +17,16 @@ Design invariants (documented in ROADMAP "Workflow"):
   staged on ONE transaction (``merge.plan_merge``) and committed at ONE
   timestamp; any conflict or failing CI check raises before the commit, and
   the two-phase ``Engine._commit`` unwinds seal-time failures — so a
-  partial publish is impossible. The WAL carries a single replayable
-  ``publish`` record.
+  partial publish is impossible. The plan the checks validated is the plan
+  committed: a table whose base, head and merge base have not moved since
+  the preview commits the preview's staged edits instead of planning
+  again. The WAL carries a single replayable ``publish`` record.
 * **CI checks** run against an *ephemeral isolated preview*: a scratch
   engine sharing the immutable object store, holding metadata clones of the
   base tables with the PR merged in. On exit every preview object is
   deleted and the oid counter rolled back, so previews are invisible to the
-  WAL, the live timestamp sequence, and replay determinism.
+  WAL, the live timestamp sequence, and replay determinism. (A check that
+  commits to the live engine keeps its objects, and the counter stays.)
 * **Revert** applies the *inverse* signed delta as a NEW commit — history
   is preserved (the published state stays reachable via PITR) and the work
   is ∝ Δ, never ∝ table size. Strict by value: if the current row is no
@@ -42,7 +45,7 @@ from ..kernels import ops
 from . import telemetry
 from .delta import signed_delta
 from .diff import DiffResult, gather_payload, gather_rowsigs, snapshot_diff
-from .directory import Snapshot
+from .directory import Directory, Snapshot
 from .merge import (OP_DEL, OP_INS, ConflictMode, MergeConflictError,
                     MergeReport, collapse_pk, plan_merge)
 from .faults import crash_point, register
@@ -218,6 +221,29 @@ def drop_branch(engine, name: str, *, _log=True) -> None:
 # pull requests
 # --------------------------------------------------------------------------
 
+@dataclass
+class _PreviewPlan:
+    """One table's merge as the checks' preview planned it: the edits it
+    staged (``Txn.staged_edits``), its report, and what it was planned
+    from."""
+    edits: Tuple[list, list, list]
+    report: MergeReport
+    mode: ConflictMode
+    target_dir: Directory
+    src: Snapshot
+    merge_base: Optional[Snapshot]
+
+    def unmoved(self, target: Table, src: Snapshot,
+                merge_base: Optional[Snapshot], mode: ConflictMode) -> bool:
+        """True iff planning again from these inputs would stage the same
+        edits: the same mode, and the very directories and merge base the
+        preview planned from."""
+        return (mode is self.mode and merge_base is self.merge_base
+                and target.directory is self.target_dir
+                and src.directory is self.src.directory
+                and src.schema is self.src.schema)
+
+
 class CheckContext:
     """Read view a CI check gets: the ephemeral merged preview tables."""
 
@@ -312,15 +338,19 @@ class PullRequest:
         self.checks.append((name or getattr(fn, "__name__", "check"), fn))
 
     @contextlib.contextmanager
-    def _merged_preview(self, mode: ConflictMode):
+    def _merged_preview(self, mode: ConflictMode,
+                        plans: Optional[Dict[str, _PreviewPlan]] = None):
         """Ephemeral isolated clone of the base tables with this PR merged
         in. Shares the immutable object store; on exit every object sealed
         for the preview is deleted and the oid counter rolled back, so the
-        preview never perturbs the WAL, the timestamp sequence, or replay."""
+        preview never perturbs the WAL, the timestamp sequence, or replay;
+        objects a check sealed for the live engine stay. When every table
+        planned without a conflict, ``plans`` receives each table's plan
+        for publish to commit."""
         from .engine import Engine
         engine = self.engine
         store = engine.store
-        oid0 = store._next_oid
+        oid0, ts0 = store._next_oid, engine.ts
         scratch = Engine()
         scratch.store = store
         scratch.ts = engine.ts
@@ -336,26 +366,40 @@ class PullRequest:
                 mapping[lg] = lg
             tx = scratch.begin()
             try:
+                kept: Dict[str, _PreviewPlan] = {}
                 for lg, phys in self.tables.items():
-                    plan_merge(scratch, lg,
-                               engine.current_snapshot(phys),
-                               self._merge_base(lg), mode, MergeReport(), tx)
+                    base = self._merge_base(lg)
+                    report = MergeReport(used_base=base is not None)
+                    src = engine.current_snapshot(phys)
+                    target_dir = scratch.tables[lg].directory
+                    plan_merge(scratch, lg, src, base, mode, report, tx)
+                    kept[lg] = _PreviewPlan(tx.staged_edits(lg), report,
+                                            mode, target_dir, src, base)
+                if plans is not None:
+                    plans.update(kept)
                 if tx.staged:
                     tx.commit(_log=False)
             except MergeConflictError as exc:
                 merge_err = exc
             yield scratch, mapping, merge_err
         finally:
+            # a check that committed to the live engine sealed objects in
+            # the preview's oid range: those stay, and so does the counter
+            live = _live_oids_since(engine, oid0) if engine.ts != ts0 else ()
             for oid in range(oid0, store._next_oid):
-                if store.has(oid):
+                if oid not in live and store.has(oid):
                     store.delete(oid)
-            store._next_oid = oid0
+            if not live:
+                store._next_oid = oid0
 
-    def run_checks(self, mode: ConflictMode = ConflictMode.FAIL
+    def run_checks(self, mode: ConflictMode = ConflictMode.FAIL, *,
+                   _plans: Optional[Dict[str, _PreviewPlan]] = None
                    ) -> List[CheckResult]:
-        """Run every registered check against the ephemeral merged preview."""
+        """Run every registered check against the ephemeral merged preview.
+        ``_plans`` (publish only) receives the preview's merge plans."""
         results: List[CheckResult] = []
-        with self._merged_preview(mode) as (scratch, mapping, merge_err):
+        with self._merged_preview(mode, _plans) as (scratch, mapping,
+                                                    merge_err):
             if merge_err is not None:
                 return [CheckResult(
                     "merge", False,
@@ -383,6 +427,8 @@ class PullRequest:
         Order of gates: CI checks (ephemeral preview) -> per-table merge
         planning (conflicts raise with nothing staged) -> ONE multi-table
         commit at ONE timestamp (two-phase, unwinds on seal-time failure).
+        A table whose inputs have not moved since the preview stages the
+        preview's plan rather than planning again.
         The WAL carries a single replayable ``publish`` record."""
         with telemetry.span(SP_PUBLISH):
             return self._publish(mode, _log, _skip_checks)
@@ -392,8 +438,9 @@ class PullRequest:
         if self.status != "open":
             raise ValueError(f"PR #{self.id} is {self.status}, not open")
         engine = self.engine
+        kept: Dict[str, _PreviewPlan] = {}
         if self.checks and not _skip_checks:
-            results = self.run_checks(mode)
+            results = self.run_checks(mode, _plans=kept)
             if any(not r.ok for r in results):
                 # a conflicting preview (the synthetic result) falls
                 # through to planning below, which raises the genuine
@@ -407,12 +454,20 @@ class PullRequest:
         tx = engine.begin()
         planned: Dict[str, Tuple[MergeReport, Snapshot]] = {}
         for lg, phys in self.tables.items():
-            report = MergeReport()
+            target = self._base_physical(lg)
             base = self._merge_base(lg)
-            report.used_base = base is not None
             src = engine.current_snapshot(phys)
-            plan_merge(engine, self._base_physical(lg), src, base, mode,
-                       report, tx)
+            plan = kept.get(lg)
+            if plan is not None and plan.unmoved(engine.table(target), src,
+                                                 base, mode):
+                tx.restage(target, plan.edits)
+                report = plan.report
+                engine.store.metrics.add("publish.plans_reused")
+            else:
+                if plan is not None:
+                    engine.store.metrics.add("publish.plans_replanned")
+                report = MergeReport(used_base=base is not None)
+                plan_merge(engine, target, src, base, mode, report, tx)
             planned[lg] = (report, src)
         crash_point(CP_PUBLISH_PLANNED)
         with engine.op_kind("publish"):
@@ -463,6 +518,12 @@ class PullRequest:
         self.status = "closed"
         if _log:
             self.engine.wal.append("close_pr", pr=self.id)
+
+
+def _live_oids_since(engine, oid0: int) -> set:
+    """Objects from ``oid0`` on that some version of a live table holds."""
+    return {o for t in engine.tables.values() for _, d in t.history
+            for o in (*d.data_oids, *d.tomb_oids) if o >= oid0}
 
 
 def open_pr(engine, base: Optional[str], head: str, *,

@@ -109,6 +109,32 @@ def test_crash_sweep_all_or_nothing(point, oracle):
         assert report.ok, (point, n, [str(i) for i in report.issues])
 
 
+@pytest.mark.parametrize("point", ["workspace.publish.planned",
+                                   "workspace.publish.pre_log"])
+def test_crash_in_checked_publish_recovers_no_publish(point):
+    """A publish whose checks' preview plan is committed as it was: a kill
+    after planning or before the publish record recovers to no publish."""
+    e = Engine()
+    e.create_table("t", SCH)
+    e.create_table("u", SCH)
+    e.insert("t", _batch([1, 2, 3]))
+    e.insert("u", _batch([10]))
+    e.create_branch("dev", ["t", "u"])
+    e.update_by_keys("dev/t", _batch([2], vals=[7.0]))
+    e.insert("dev/u", _batch([11]))
+    pr = e.open_pr("main", "dev")
+    pr.add_check(lambda ctx: ctx.count("t") == 3, "row-count")
+    before = digests(e)
+    with inject(FaultPlan.at(point)):
+        with pytest.raises(InjectedCrash):
+            pr.publish()
+    assert e.store.metrics.counters["publish.plans_reused"] == 2
+    recovered = Engine.replay(WAL.deserialize(e.wal.serialize()))
+    assert digests(recovered) == before
+    assert recovered.prs[pr.id].status == "open"
+    assert fsck(recovered).ok
+
+
 def test_mid_swing_crash_recovers_whole_transaction():
     """Log-before-swing: by the time the first directory swings, the FULL
     commit group is in the WAL — a mid-swing kill recovers to ALL tables

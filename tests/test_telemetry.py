@@ -54,6 +54,8 @@ PINNED_METRICS = [
     "probe.objects_pruned",
     "probe.queries",
     "probe.shard_parts",
+    "publish.plans_replanned",
+    "publish.plans_reused",
     "store.bytes_packed",
     "store.evictions",
     "store.faults",
@@ -130,7 +132,7 @@ def test_stats_json_golden_schema():
     repo = _mk_repo()
     doc = telemetry.stats_json(repo.engine)
     assert set(doc) == {"schema", "metrics"}
-    assert doc["schema"] == telemetry.STATS_SCHEMA == 6
+    assert doc["schema"] == telemetry.STATS_SCHEMA == 7
     assert list(doc["metrics"]) == PINNED_METRICS  # sorted AND complete
     # engine=None (CLI arms before the store loads): same keys, all zero
     empty = telemetry.stats_json(None)

@@ -451,3 +451,205 @@ def test_full_workflow_e2e_wal_replay():
     assert set(e2.branches) == set(e.branches) == set()
     assert {i: p.status for i, p in e2.prs.items()} == \
         {i: p.status for i, p in e.prs.items()}
+
+
+# ------------------------------------ publish commits the checks' own plan
+
+def _pk_fail(e):
+    e.create_branch("dev", ["t"])
+    e.update_by_keys("dev/t", _batch([2], vals=[99.0]))
+    e.insert("dev/t", _batch([4, 5]))
+    e.delete_by_keys("dev/t", {"k": np.asarray([1])})
+    e.insert("t", _batch([7]))                        # base moved, no overlap
+    return e.open_pr("main", "dev"), ConflictMode.FAIL
+
+
+def _nopk(e):
+    e.create_branch("dev", ["t"])
+    e.insert("dev/t", _batch([2, 9]))                 # a duplicate and a new
+    tx = e.begin()
+    tx.delete_rowids("dev/t", e.table("dev/t").scan()[1][:1])
+    tx.commit()
+    e.insert("t", _batch([8]))
+    return e.open_pr("main", "dev"), ConflictMode.FAIL
+
+
+def _pk_accept_conflicts(e):
+    e.create_branch("dev", ["t", "u"])
+    e.update_by_keys("dev/t", _batch([2, 3], vals=[9.0, 8.0]))
+    e.update_by_keys("t", _batch([2, 3], vals=[5.0, 4.0]))
+    e.insert("dev/u", _batch([30]))
+    return e.open_pr("main", "dev"), ConflictMode.ACCEPT
+
+
+def _cell(e):
+    e.create_branch("dev", ["t"])
+    e.update_by_keys("dev/t", _batch([2], docs=[b"head"]))
+    e.update_by_keys("t", _batch([2], vals=[9.0]))
+    return e.open_pr("main", "dev"), ConflictMode.CELL
+
+
+def _two_tables_into_branch(e):
+    e.create_branch("rel", ["t", "u"])
+    e.insert("rel/t", _batch([40]))
+    e.create_branch("dev", ["t", "u"], from_ref="rel")
+    e.update_by_keys("dev/t", _batch([3], vals=[7.0]))
+    e.insert("dev/u", _batch([31, 32]))
+    e.insert("rel/u", _batch([50]))
+    return e.open_pr("rel", "dev"), ConflictMode.FAIL
+
+
+def _indexed(e):
+    from repro.core.indices import create_index
+    create_index(e, "t", "by_doc", ["doc"])
+    e.create_branch("dev", ["t"])
+    e.update_by_keys("dev/t", _batch([2], docs=[b"renamed"]))
+    e.insert("dev/t", _batch([6]))
+    return e.open_pr("main", "dev"), ConflictMode.FAIL
+
+
+PLAN_CASES = {"pk_fail": _pk_fail, "nopk": _nopk,
+              "pk_accept_conflicts": _pk_accept_conflicts, "cell": _cell,
+              "two_tables_into_branch": _two_tables_into_branch,
+              "indexed": _indexed}
+
+REPORT_FIELDS = ("true_conflicts", "cell_merged", "false_conflicts",
+                 "inserted", "deleted", "used_base", "commit_ts")
+
+
+def _publish_case(case, check=None):
+    e = mk_engine(nopk=case == "nopk")
+    pr, mode = PLAN_CASES[case](e)
+    pr.add_check(check or (lambda ctx: all(
+        ctx.count(lg) >= 0 for lg in ctx.tables)), "preview-readable")
+    return e, pr, pr.publish(mode)
+
+
+def _force_replan(monkeypatch):
+    from repro.core import workspace
+    monkeypatch.setattr(workspace._PreviewPlan, "unmoved",
+                        lambda self, *a: False)
+
+
+def _assert_same_state(a, b):
+    """Every table's rows, rowids, columns and row signatures, and ts."""
+    assert a.ts == b.ts
+    assert sorted(a.tables) == sorted(b.tables)
+    for name in a.tables:
+        ba, ra, la, ha = a.table(name).scan(with_sigs=True)
+        bb, rb, lb, hb = b.table(name).scan(with_sigs=True)
+        assert ra.tolist() == rb.tolist(), name
+        assert sorted(ba) == sorted(bb)
+        for c in ba:
+            assert ba[c].tolist() == bb[c].tolist(), (name, c)
+        assert la.tolist() == lb.tolist() and ha.tolist() == hb.tolist()
+
+
+def _counter(e, name):
+    return e.store.metrics.counters.get(name, 0)
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_publish_commits_the_preview_plan(case, monkeypatch):
+    """Publishing with a check commits the plan the checks' preview made,
+    and lands exactly what planning again lands: the same rows, rowids and
+    signatures, commit ts, reports and WAL."""
+    a, pr_a, rep_a = _publish_case(case)
+    assert _counter(a, "publish.plans_reused") == len(pr_a.tables)
+    assert _counter(a, "publish.plans_replanned") == 0
+    with monkeypatch.context() as m:
+        _force_replan(m)
+        b, pr_b, rep_b = _publish_case(case)
+    assert _counter(b, "publish.plans_reused") == 0
+    assert _counter(b, "publish.plans_replanned") == len(pr_b.tables)
+    assert pr_a.publish_ts == pr_b.publish_ts is not None
+    assert sorted(rep_a) == sorted(rep_b)
+    for lg in rep_a:
+        for f in REPORT_FIELDS:
+            assert getattr(rep_a[lg], f) == getattr(rep_b[lg], f), (lg, f)
+        assert (rep_a[lg].conflict_key_lo.tolist()
+                == rep_b[lg].conflict_key_lo.tolist())
+    _assert_same_state(a, b)
+    assert a.wal.serialize() == b.wal.serialize()
+    if case == "pk_accept_conflicts":
+        assert rep_a["t"].true_conflicts == 2
+    if case == "cell":
+        assert rep_a["t"].cell_merged == 1
+    if case == "indexed":
+        # maintenance ran on the reused plan: the base's index finds the
+        # published value
+        from repro.core.indices import lookup_eq
+        doc = np.asarray([b"renamed"], dtype=object)
+        assert lookup_eq(a, "t", "by_doc", {"doc": doc})["k"].tolist() == [2]
+    # the WAL, replayed (no checks, one plan), rebuilds the same state
+    _assert_same_state(a, Engine.replay(WAL.deserialize(a.wal.serialize())))
+
+
+def test_check_moving_the_live_base_replans_that_table(monkeypatch):
+    """A check that commits to the live base table moves it after the
+    preview: publish plans that table again, reuses the other, and lands
+    what a fresh plan lands."""
+    def history():
+        e = mk_engine()
+        e.create_branch("dev", ["t", "u"])
+        e.update_by_keys("dev/t", _batch([2], vals=[99.0]))
+        e.insert("dev/u", _batch([30]))
+        pr = e.open_pr("main", "dev")
+        done = []
+
+        def moves_base(ctx):
+            if not done:
+                e.insert("t", _batch([500]))
+                done.append(1)
+            return True
+
+        pr.add_check(moves_base, "moves-base")
+        return e, pr, pr.publish()
+
+    a, pr_a, rep_a = history()
+    assert _counter(a, "publish.plans_replanned") == 1
+    assert _counter(a, "publish.plans_reused") == 1
+    assert 500 in a.table("t").scan()[0]["k"].tolist()
+    assert 99.0 in a.table("t").scan()[0]["v"].tolist()
+    with monkeypatch.context() as m:
+        _force_replan(m)
+        b, pr_b, rep_b = history()
+    assert _counter(b, "publish.plans_replanned") == 2
+    _assert_same_state(a, b)
+    for lg in rep_a:
+        for f in REPORT_FIELDS:
+            assert getattr(rep_a[lg], f) == getattr(rep_b[lg], f), (lg, f)
+
+
+@pytest.mark.parametrize("tables", [["t"], ["t", "u"]])
+def test_conflicting_preview_raises_full_report_untouched(tables):
+    """Under FAIL a conflicting preview keeps no plan: publish plans and
+    raises the genuine MergeConflictError with the report a publish
+    without checks gives, and leaves the store, ts and WAL untouched."""
+    def history(with_check):
+        e = mk_engine()
+        e.create_branch("dev", tables)
+        e.update_by_keys("dev/t", _batch([2, 3], vals=[9.0, 8.0]))
+        e.update_by_keys("t", _batch([2, 3], vals=[5.0, 4.0]))
+        pr = e.open_pr("main", "dev")
+        if with_check:
+            pr.add_check(lambda ctx: True, "always-green")
+        return e, pr
+
+    want = None
+    for with_check in (False, True):
+        e, pr = history(with_check)
+        ts0, oids0, wal0 = e.ts, set(e.store.oids()), e.wal.serialize()
+        with pytest.raises(MergeConflictError) as exc:
+            pr.publish(mode=ConflictMode.FAIL)
+        report = exc.value.report
+        got = (report.true_conflicts, report.conflict_key_lo.tolist(),
+               report.conflict_key_hi.tolist())
+        assert report.true_conflicts == 2
+        want = want or got
+        assert got == want
+        assert (e.ts, set(e.store.oids()), e.wal.serialize()) == \
+            (ts0, oids0, wal0)
+        assert pr.status == "open"
+        assert _counter(e, "publish.plans_reused") == 0
+        assert _counter(e, "publish.plans_replanned") == 0
